@@ -32,7 +32,9 @@ On CPU the collectives are in-process memcpy, so this measures the sharding
 overhead floor, not real scaling — the point is that the overhead stays flat
 while per-device work shrinks (the dry-run roofline covers real meshes).
 Device counts the host cannot force (or that time out) are skipped with a
-note rather than failing the harness.
+note rather than failing the harness.  The children always run with
+``JAX_PLATFORMS=cpu``, so every row (and the record's ``platform``) is a
+CPU row, and no child contends for an accelerator the parent holds.
 """
 
 from __future__ import annotations
@@ -158,7 +160,9 @@ print(json.dumps(rec))
 def _spawn(code: str, devices: int, tag: str) -> dict | None:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
-    env["JAX_PLATFORMS"] = env.get("JAX_PLATFORMS", "cpu")
+    # the children run on forced host devices, never on an accelerator the
+    # parent process may already hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = "src" + os.pathsep + env.get("PYTHONPATH", "")
     try:
         out = subprocess.run(
@@ -191,7 +195,7 @@ def main() -> None:
     note(f"distributed scaling: n={N} d={D} t={T}, rows-only meshes, "
          f"devices {DEVICE_COUNTS}" + (" [smoke]" if SMOKE else ""))
     base: dict | None = None
-    record: dict = {"smoke": SMOKE, "n": N, "d": D, "t": T,
+    record: dict = {"platform": "cpu", "smoke": SMOKE, "n": N, "d": D, "t": T,
                     "device_counts": list(DEVICE_COUNTS), "points": {}}
     for devices in DEVICE_COUNTS:
         res = _run_point(devices)
@@ -203,11 +207,12 @@ def main() -> None:
         for key, tag in (("matvec_us", "matvec"), ("askotch_us", "askotch")):
             speedup = base[key] / res[key] if base else 1.0
             emit(f"dist_{tag}_dev{devices}", res[key],
-                 f"speedup_vs_1dev={speedup:.2f}")
+                 f"platform=cpu_speedup_vs_1dev={speedup:.2f}")
         if "tune_us" in res:
             speedup = base["tune_us"] / res["tune_us"]
             emit(f"dist_tune_dev{devices}", res["tune_us"],
-                 f"sweeps={res['tune_sweeps']:.1f}_speedup_vs_1dev={speedup:.2f}")
+                 f"platform=cpu_sweeps={res['tune_sweeps']:.1f}"
+                 f"_speedup_vs_1dev={speedup:.2f}")
 
     # the accuracy/communication frontier: DC vs sharded, same device count
     for devices in DEVICE_COUNTS:
@@ -218,7 +223,7 @@ def main() -> None:
         speedup = res["sharded_wall_s"] / res["dc_wall_s"]
         emit(
             f"dc_dev{devices}", res["dc_wall_s"] * 1e6,
-            f"collectives={res['dc_collectives']:.0f}"
+            f"platform=cpu_collectives={res['dc_collectives']:.0f}"
             f"_vs_sharded={res['sharded_collectives']:.0f}"
             f"_speedup_vs_sharded={speedup:.2f}"
             f"_rmse_delta={res['dc_rmse'] - res['sharded_rmse']:+.4f}",

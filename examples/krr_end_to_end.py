@@ -32,10 +32,10 @@ def main() -> None:
     x, y = synthetic.taxi_like(0, n + 5000, 9)
     x_tr, y_tr, x_te, y_te = x[:n], y[:n], x[n:], y[n:]
     prob = KRRProblem(x=x_tr, y=y_tr, kernel="rbf", sigma=1.0,
-                      lam_unscaled=2e-7, backend="xla")
+                      lam_unscaled=2e-7)
 
     # --- ASkotch with mid-solve checkpoint/restart -------------------------
-    cfg = ASkotchConfig(backend="xla")
+    cfg = ASkotchConfig()
     step = jax.jit(make_step(prob, cfg))
     state = init_state(prob)
     t0 = time.perf_counter()

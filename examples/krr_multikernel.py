@@ -48,7 +48,7 @@ def main() -> None:
     x_tr = jnp.asarray(x[: args.n])
     y_tr = jnp.asarray(y[: args.n])
     x_te, y_te = x[args.n :], y[args.n :]
-    prob = KRRProblem(x=x_tr, y=y_tr, backend="xla")
+    prob = KRRProblem(x=x_tr, y=y_tr)
 
     # 1. weight search: every (w, lam, fold) candidate shares kernel tiles
     result = tune(

@@ -30,7 +30,7 @@ def main() -> None:
     x_tr, y_tr, _, x_te, y_te, labels_te = synthetic.krr_one_vs_all(
         0, args.n, args.d, num_classes=args.classes, n_test=args.n_test
     )
-    prob = KRRProblem(x=x_tr, y=y_tr, backend="xla")
+    prob = KRRProblem(x=x_tr, y=y_tr)
 
     # 1. tune: all (sigma, lam) candidates x folds x heads share kernel tiles
     result = tune(

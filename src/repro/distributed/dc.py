@@ -47,6 +47,7 @@ the single shard's prediction verbatim.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from concurrent.futures import ThreadPoolExecutor
@@ -223,8 +224,13 @@ def solve_dc(
     devices = list(mesh.devices.flat) if mesh is not None else [None]
 
     def run_shard(s: int):
-        sub = _shard_problem(problem, shard_idx[s], devices[s % len(devices)])
-        with tel.span("dc/shard", shard=s, n=sub.n, method=method):
+        device = devices[s % len(devices)]
+        sub = _shard_problem(problem, shard_idx[s], device)
+        # the local solvers close over x and start from fresh arrays: the
+        # default device, not the inputs, decides where their steps run
+        place = jax.default_device(device) if device is not None else (
+            contextlib.nullcontext())
+        with place, tel.span("dc/shard", shard=s, n=sub.n, method=method):
             return solve(sub, method, telemetry=telemetry, **kw)
 
     before = obs_metrics.snapshot()

@@ -53,7 +53,7 @@ from repro.core.kernels import KERNEL_NAMES
 from repro.core.krr import KRRProblem, residual_report, scaled_lam
 from repro.core.nystrom import nystrom_from_sketch
 from repro.core.operator import as_multirhs, maybe_squeeze
-from repro.distributed.jax_compat import shard_map
+from repro.distributed.meshes import shard_map
 from repro.distributed.sharded_operator import ShardedKernelOperator
 from repro.kernels.precision import PRECISIONS
 
@@ -90,7 +90,7 @@ class DistKRRConfig:
     #   statistically exchangeable under uniform sampling, so the top
     #   preconditioned eigenvector varies little between iterations
     powering_warm_iters: int = 3
-    backend: str = "xla"  # local compute backend inside shards
+    backend: str = "auto"  # local compute backend inside shards
     precision: str = "f32"  # kernel tile-compute policy: "f32" | "bf16"
 
     def __post_init__(self) -> None:
@@ -324,7 +324,7 @@ def solve_askotch_dist(
     mu: float | None = None,
     nu: float | None = None,
     powering_iters: int = 10,
-    backend: str = "xla",
+    backend: str = "auto",
     max_iters: int = 500,
     tol: float = 1e-8,
     eval_every: int = 25,
@@ -391,7 +391,7 @@ def solve_pcg_dist(
     precond: str = "nystrom",
     rank: int = 100,
     rho_mode: str = "damped",
-    backend: str = "xla",
+    backend: str = "auto",
     max_iters: int = 200,
     tol: float = 1e-8,
     seed: int = 0,

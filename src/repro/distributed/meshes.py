@@ -14,16 +14,40 @@ restore onto a different topology) a pure launcher concern.
 from __future__ import annotations
 
 import contextlib
+import functools
 import threading
 from typing import Any
 
 import jax
+import numpy as np
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed import jax_compat
-
 _STATE = threading.local()
+
+#: ``jax.shard_map`` without the replication check: the distributed
+#: operator's out_specs are replicated where declared, but the checker cannot
+#: always prove it through ``dynamic_slice`` + ``all_gather`` chains.
+shard_map = functools.partial(jax.shard_map, check_vma=False)
+
+
+def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> Mesh:
+    """Build a Mesh over the first ``prod(shape)`` devices.
+
+    Unlike ``jax.make_mesh`` this accepts a shape smaller than the device
+    count (it slices), which is what lets a size-1 solver mesh run inside a
+    plain single-device pytest process.
+    """
+    size = int(np.prod(shape))
+    devices = jax.devices()
+    if size > len(devices):
+        raise ValueError(
+            f"mesh shape {shape} needs {size} devices; "
+            f"only {len(devices)} available (set "
+            f"XLA_FLAGS=--xla_force_host_platform_device_count={size} for a "
+            f"host-platform test mesh)"
+        )
+    return Mesh(np.asarray(devices[:size]).reshape(shape), axes)
 
 
 def parse_mesh_spec(spec: str) -> tuple[int, int]:
@@ -55,7 +79,7 @@ def make_solver_mesh(spec: str | tuple[int, int] | None = None) -> Mesh:
         rows, model = parse_mesh_spec(spec if isinstance(spec, str) else "auto")
     else:
         rows, model = spec
-    return jax_compat.make_mesh((rows, model), ("data", "model"))
+    return make_mesh((rows, model), ("data", "model"))
 
 
 def default_rules(mesh: Mesh) -> dict[str, Any]:

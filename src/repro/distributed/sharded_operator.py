@@ -67,7 +67,7 @@ from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.core.operator import KernelOperator
-from repro.distributed.jax_compat import shard_map
+from repro.distributed.meshes import shard_map
 from repro.obs.metrics import counter as _obs_counter
 
 MODEL_AXIS = "model"

@@ -2,8 +2,9 @@
 
 Skotch/ASkotch materialize the b x b block K_BB once per iteration (Nystrom
 sketch input + powering matvecs reuse it).  This kernel builds it tile by
-tile — pairwise distance on the MXU (or VPU slab-reduction for L1) fused with
-the elementwise kernel map, writing each (bm, bn) tile straight from VMEM.
+tile — pairwise distance on the MXU (or a VPU column reduction for L1)
+fused with the elementwise kernel map, writing each (bm, bn) tile straight
+from VMEM.
 
 Same tiling contract as kernel_matvec (see that module's docstring).
 """
@@ -19,9 +20,9 @@ from jax.experimental import pallas as pl
 from repro.kernels.kernel_matvec import _apply_kernel, _cast_tiles, _distance_tile
 
 
-def _block_body(a_ref, b_ref, o_ref, *, kernel: str, sigma: float, dchunk: int):
+def _block_body(a_ref, b_ref, o_ref, *, kernel: str, sigma: float):
     # operand tiles at policy width (f32/bf16); distance + map + output f32
-    dist = _distance_tile(a_ref[...], b_ref[...], kernel, dchunk)
+    dist = _distance_tile(a_ref[...], b_ref[...], kernel)
     o_ref[...] = _apply_kernel(dist, kernel, sigma)
 
 
@@ -58,9 +59,7 @@ def kernel_block_pallas(
     a_p, b_p = _cast_tiles(precision, a_p, b_p)
 
     out = pl.pallas_call(
-        functools.partial(
-            _block_body, kernel=kernel, sigma=float(sigma), dchunk=dchunk
-        ),
+        functools.partial(_block_body, kernel=kernel, sigma=float(sigma)),
         grid=(mp // bm, np_ // bn),
         in_specs=[
             pl.BlockSpec((bm, dp), lambda i, j: (i, 0)),

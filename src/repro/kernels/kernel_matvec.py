@@ -17,12 +17,15 @@ TPU-native tiling (see docs/architecture.md, "Pallas matvec tiling"):
   f32 accumulate); the dot-product family (linear/polynomial/sigmoid) and
   cosine skip the norm terms and use the raw (or row-normalized) a.b^T matmul
   directly — same MXU shape, strictly less VPU work.  The L1 distance
-  (laplacian) has no matmul form, so we stream the feature dim in ``dchunk``
-  slabs and reduce |a-b| on the VPU, bounding the (bm, bn, dchunk) broadcast
-  slab to ~2 MB of VMEM.
+  (laplacian) has no matmul form: it is reduced on the VPU one feature
+  column at a time, each a (bm, 1) - (1, bn) broadcast into the (bm, bn) f32
+  accumulator, so no 3-D slab is ever built (one (256, 256) f32 tile is
+  256 KB).
 
-  Default bm=bn=256, d padded to a multiple of 8, kv padded to 128: the MXU
-  matmuls are (256,d)x(d,256) and (256,256)x(256,kv) — both 128-aligned.
+  Default bm=bn=256, d zero-padded to a multiple of ``dchunk`` (32), kv
+  zero-padded to 128 lanes: the MXU matmuls are (256,dp)x(dp,256) and
+  (256,256)x(256,kv).  Zero feature columns leave every family's base tile
+  unchanged.
 
 Validated against ``ref.kernel_matvec`` in interpret mode (tests sweep shapes,
 dtypes and kernels); on TPU hardware the same code runs compiled.
@@ -34,7 +37,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 from jax.experimental import pallas as pl
 
 from repro.core.kernels import kernel_family
@@ -66,15 +68,25 @@ def _apply_kernel(base: jax.Array, kernel: str, sigma: float) -> jax.Array:
     raise ValueError(f"unknown kernel {kernel!r}")
 
 
-def _dot_tile(a: jax.Array, b: jax.Array) -> jax.Array:
-    """(bm, bn) f32 inner-product tile a.b^T from the MXU — operands at their
-    stored width (f32/bf16) with f32 accumulation."""
+def _mxu_dot(x: jax.Array, y: jax.Array, contract: tuple[int, int]) -> jax.Array:
+    """2-D matmul on the MXU contracting ``x``'s axis ``contract[0]`` with
+    ``y``'s axis ``contract[1]``, accumulated in f32.  f32 operands contract
+    at full f32 precision: Mosaic's default contracts them in one bf16 pass,
+    which puts percent-level error into the squared-L2 expansion."""
+    precision = jax.lax.Precision.HIGHEST if x.dtype == jnp.float32 else None
     return jax.lax.dot_general(
-        a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        x, y, (((contract[0],), (contract[1],)), ((), ())),
+        precision=precision, preferred_element_type=jnp.float32,
     )
 
 
-def _base_tile(a: jax.Array, b: jax.Array, family: str, dchunk: int) -> jax.Array:
+def _dot_tile(a: jax.Array, b: jax.Array) -> jax.Array:
+    """(bm, bn) f32 inner-product tile a.b^T from the MXU — operands at their
+    stored width (f32/bf16) with f32 accumulation."""
+    return _mxu_dot(a, b, (1, 1))
+
+
+def _base_tile(a: jax.Array, b: jax.Array, family: str) -> jax.Array:
     """(bm, bn) f32 base tile for a kernel family: squared-L2 ("l2"), L1
     ("l1"), inner product ("dot"), or cosine similarity ("cos").
 
@@ -88,19 +100,14 @@ def _base_tile(a: jax.Array, b: jax.Array, family: str, dchunk: int) -> jax.Arra
     never pollutes live rows in any family.
     """
     if family == "l1":
-        bm, d = a.shape
-        bn = b.shape[0]
-        nchunks = d // dchunk  # d is pre-padded to a multiple of dchunk
-
-        def body(c, acc):
-            a_s = lax.dynamic_slice(a, (0, c * dchunk), (bm, dchunk))
-            b_s = lax.dynamic_slice(b, (0, c * dchunk), (bn, dchunk))
-            diff = a_s[:, None, :].astype(jnp.float32) - b_s[None, :, :].astype(
-                jnp.float32
-            )
-            return acc + jnp.sum(jnp.abs(diff), axis=-1)
-
-        return lax.fori_loop(0, nchunks, body, jnp.zeros((bm, bn), jnp.float32))
+        # one feature column at a time: (bm, 1) - (1, bn) broadcasts reduce
+        # |a - b| on the VPU without a 3-D slab.  Zero-padded columns add 0.
+        af = a.astype(jnp.float32)
+        bf = b.astype(jnp.float32)
+        acc = jnp.zeros((a.shape[0], b.shape[0]), jnp.float32)
+        for c in range(a.shape[1]):
+            acc += jnp.abs(af[:, c : c + 1] - bf[:, c : c + 1].T)
+        return acc
     if family == "dot":
         return _dot_tile(a, b)
     if family == "cos":
@@ -120,10 +127,10 @@ def _base_tile(a: jax.Array, b: jax.Array, family: str, dchunk: int) -> jax.Arra
     return jnp.maximum(aa + bb - 2.0 * ab, 0.0)
 
 
-def _distance_tile(a: jax.Array, b: jax.Array, kernel: str, dchunk: int) -> jax.Array:
+def _distance_tile(a: jax.Array, b: jax.Array, kernel: str) -> jax.Array:
     """Base tile for one kernel — :func:`_base_tile` keyed by the kernel's
     family (kept as the per-kernel spelling the single-kernel bodies use)."""
-    return _base_tile(a, b, kernel_family(kernel), dchunk)
+    return _base_tile(a, b, kernel_family(kernel))
 
 
 def _cast_tiles(precision: str, *arrays: jax.Array) -> tuple[jax.Array, ...]:
@@ -134,7 +141,7 @@ def _cast_tiles(precision: str, *arrays: jax.Array) -> tuple[jax.Array, ...]:
     return arrays
 
 
-def _matvec_body(a_ref, b_ref, v_ref, o_ref, *, kernel: str, sigma: float, dchunk: int):
+def _matvec_body(a_ref, b_ref, v_ref, o_ref, *, kernel: str, sigma: float):
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -145,14 +152,9 @@ def _matvec_body(a_ref, b_ref, v_ref, o_ref, *, kernel: str, sigma: float, dchun
     # kernel map are f32, the second matmul runs at policy width with f32
     # accumulation (preferred_element_type) into the resident o_ref tile
     v = v_ref[...]
-    dist = _distance_tile(a_ref[...], b_ref[...], kernel, dchunk)
+    dist = _distance_tile(a_ref[...], b_ref[...], kernel)
     ktile = _apply_kernel(dist, kernel, sigma)
-    o_ref[...] += jax.lax.dot_general(
-        ktile.astype(v.dtype),
-        v,
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    o_ref[...] += _mxu_dot(ktile.astype(v.dtype), v, (1, 0))
 
 
 @functools.partial(
@@ -200,9 +202,7 @@ def kernel_matvec_pallas(
     a_p, b_p, v_p = _cast_tiles(precision, a_p, b_p, v_p)
 
     out = pl.pallas_call(
-        functools.partial(
-            _matvec_body, kernel=kernel, sigma=float(sigma), dchunk=dchunk
-        ),
+        functools.partial(_matvec_body, kernel=kernel, sigma=float(sigma)),
         grid=(mp // bm, np_ // bn),
         in_specs=[
             pl.BlockSpec((bm, dp), lambda i, j: (i, 0)),

@@ -4,10 +4,11 @@ A weighted-sum kernel ``K_w = sum_i w_i K_i`` (q base kernels, weights w on
 the simplex) costs the same data movement as a single kernel: per (bm, bn)
 tile the pairwise base tile is computed at most once per kernel FAMILY
 (``core.kernels.KERNEL_FAMILIES``: squared-L2 on the MXU for rbf/matern52,
-L1 slab-reduction on the VPU for laplacian, raw / normalized a.b^T for the
-dot-product and cosine kernels) and the q elementwise kernel maps + weighted
-accumulation stay in VMEM.  This is what makes a q-kernel operator sweep
-cost ~1 kernel sweep instead of q (docs/tuning.md, "Multi-kernel sweeps").
+a per-column L1 reduction on the VPU for laplacian, raw / normalized a.b^T
+for the dot-product and cosine kernels) and the q elementwise kernel maps +
+weighted accumulation stay in VMEM.  This is what makes a q-kernel operator
+sweep cost ~1 kernel sweep instead of q (docs/tuning.md, "Multi-kernel
+sweeps").
 
 Three entry points, all validated against ``ref.kernel_*_multi`` in
 interpret mode:
@@ -30,18 +31,22 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 from jax.experimental import pallas as pl
 
 from repro.core.kernels import kernel_family
-from repro.kernels.kernel_matvec import _apply_kernel, _base_tile, _cast_tiles
+from repro.kernels.kernel_matvec import (
+    _apply_kernel,
+    _base_tile,
+    _cast_tiles,
+    _mxu_dot,
+)
 
 
-def _tiles(a, b, kernels, dchunk):
+def _tiles(a, b, kernels):
     """Base tiles shared by every kernel map, one per family present
     ("l2"/"l1"/"dot"/"cos" -> (bm, bn) f32 tile)."""
     return {
-        fam: _base_tile(a, b, fam, dchunk)
+        fam: _base_tile(a, b, fam)
         for fam in dict.fromkeys(kernel_family(k) for k in kernels)
     }
 
@@ -51,7 +56,7 @@ def _tile_for(kernel, tiles, sigma):
 
 
 def _multi_matvec_body(
-    a_ref, b_ref, v_ref, w_ref, o_ref, *, kernels, sigmas, dchunk
+    a_ref, b_ref, v_ref, w_ref, o_ref, *, kernels, sigmas
 ):
     j = pl.program_id(1)
 
@@ -63,22 +68,21 @@ def _multi_matvec_body(
     # and the accumulator stay f32, the per-kernel matmul runs at policy
     # width with f32 accumulation
     v = v_ref[...]
-    tiles = _tiles(a_ref[...], b_ref[...], kernels, dchunk)
+    tiles = _tiles(a_ref[...], b_ref[...], kernels)
     acc = jnp.zeros_like(o_ref)
     for i, (kn, sg) in enumerate(zip(kernels, sigmas)):
         ktile = _tile_for(kn, tiles, sg)
         # w_ic (K_i v)[:, c] == (K_i (v * w_i))[:, c]: pre-scaling v per
         # kernel lets one accumulator serve every kernel and column
-        acc += lax.dot_general(
+        acc += _mxu_dot(
             ktile.astype(v.dtype),
             (v * w_ref[i, :][None, :]).astype(v.dtype),
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+            (1, 0),
         )
     o_ref[...] += acc
 
 
-def _components_body(a_ref, b_ref, v_ref, o_ref, *, kernels, sigmas, dchunk):
+def _components_body(a_ref, b_ref, v_ref, o_ref, *, kernels, sigmas):
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -86,17 +90,14 @@ def _components_body(a_ref, b_ref, v_ref, o_ref, *, kernels, sigmas, dchunk):
         o_ref[...] = jnp.zeros_like(o_ref)
 
     v = v_ref[...]
-    tiles = _tiles(a_ref[...], b_ref[...], kernels, dchunk)
+    tiles = _tiles(a_ref[...], b_ref[...], kernels)
     for i, (kn, sg) in enumerate(zip(kernels, sigmas)):
         ktile = _tile_for(kn, tiles, sg)
-        o_ref[i, ...] += lax.dot_general(
-            ktile.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        o_ref[i, ...] += _mxu_dot(ktile.astype(v.dtype), v, (1, 0))
 
 
-def _block_multi_body(a_ref, b_ref, o_ref, *, kernels, sigmas, weights, dchunk):
-    tiles = _tiles(a_ref[...], b_ref[...], kernels, dchunk)
+def _block_multi_body(a_ref, b_ref, o_ref, *, kernels, sigmas, weights):
+    tiles = _tiles(a_ref[...], b_ref[...], kernels)
     acc = jnp.zeros_like(o_ref)
     for kn, sg, w in zip(kernels, sigmas, weights):
         acc += w * _tile_for(kn, tiles, sg)
@@ -161,7 +162,7 @@ def kernel_matvec_multi_pallas(
 
     out = pl.pallas_call(
         functools.partial(
-            _multi_matvec_body, kernels=kernels, sigmas=sigmas, dchunk=dchunk
+            _multi_matvec_body, kernels=kernels, sigmas=sigmas
         ),
         grid=(mp // bm, np_ // bn),
         in_specs=[
@@ -211,7 +212,7 @@ def kernel_matvec_components_pallas(
 
     out = pl.pallas_call(
         functools.partial(
-            _components_body, kernels=kernels, sigmas=sigmas, dchunk=dchunk
+            _components_body, kernels=kernels, sigmas=sigmas
         ),
         grid=(mp // bm, np_ // bn),
         in_specs=[
@@ -263,7 +264,7 @@ def kernel_block_multi_pallas(
     out = pl.pallas_call(
         functools.partial(
             _block_multi_body, kernels=kernels, sigmas=sigmas,
-            weights=weights, dchunk=dchunk,
+            weights=weights,
         ),
         grid=(mp // bm, np_ // bn),
         in_specs=[

@@ -35,6 +35,9 @@ import numpy as np
 
 
 def main() -> None:
+    from repro.launch.runtime import use_compile_cache
+
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--artifact", action="append", default=[],
                     metavar="NAME=DIR",

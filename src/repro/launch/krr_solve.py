@@ -26,6 +26,7 @@ from __future__ import annotations
 import argparse
 import json
 import time
+from typing import Any, NamedTuple
 
 import numpy as np
 
@@ -33,9 +34,20 @@ from repro.core.kernels import KERNEL_NAMES
 from repro.core.krr import KRRProblem, evaluate, evaluate_per_head
 from repro.core.solver_api import solve as solve_any
 from repro.data import synthetic
+from repro.launch.runtime import device_fields, use_compile_cache
 
 
-def main() -> None:
+class SolveRun(NamedTuple):
+    report: dict  # the JSON line the CLI prints
+    out: Any  # the solver_api.SolveOutput
+    problem: KRRProblem
+    x_test: Any
+    y_test: Any
+
+
+def run(argv: list[str] | None = None) -> SolveRun:
+    """Parse ``argv`` (the command line's arguments without the program
+    name), generate the data, solve, and score on the held-out rows."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=20_000)
     ap.add_argument("--d", type=int, default=9)
@@ -72,7 +84,7 @@ def main() -> None:
     ap.add_argument("--telemetry", default=None, metavar="PATH",
                     help="write telemetry (spans + solver traces + metrics) "
                          "as JSONL to PATH (repro.obs)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     mesh_spec = args.mesh if args.mesh is not None else (
         "auto" if args.distributed else None)
@@ -92,8 +104,7 @@ def main() -> None:
         x_tr, y_tr, x_te, y_te = gen(args.seed, args.n, args.d, args.n_test)
 
     prob = KRRProblem(x=x_tr, y=y_tr, kernel=args.kernel, sigma=args.sigma,
-                      lam_unscaled=args.lam, backend="xla",
-                      precision=args.precision)
+                      lam_unscaled=args.lam, precision=args.precision)
 
     if args.method == "direct":
         kw = {}
@@ -156,6 +167,7 @@ def main() -> None:
         "test_mae": float(m.mae),
         "test_acc": float(m.accuracy),
         "seconds": round(time.perf_counter() - t0, 2),
+        **device_fields(prob.backend),
     }
     if args.telemetry:
         report["telemetry"] = args.telemetry
@@ -167,7 +179,12 @@ def main() -> None:
         if rel_heads is not None:
             report["rel_residual_per_head"] = [float(v) for v in rel_heads]
         report["test_acc_per_head"] = [float(v) for v in mh.accuracy]
-    print(json.dumps(report))
+    return SolveRun(report, out, prob, x_te, y_te)
+
+
+def main(argv: list[str] | None = None) -> None:
+    use_compile_cache()
+    print(json.dumps(run(argv).report))
 
 
 if __name__ == "__main__":

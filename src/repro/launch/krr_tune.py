@@ -50,9 +50,11 @@ from repro.core.solver_api import solve as solve_any
 from repro.core.solver_api import tune
 from repro.core.tune import apply_best
 from repro.data import synthetic
+from repro.launch.runtime import device_fields, use_compile_cache
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=4_000)
     ap.add_argument("--d", type=int, default=8)
@@ -129,7 +131,7 @@ def main() -> None:
                else synthetic.krr_regression)
         x_tr, y_tr, x_te, y_te = gen(args.seed, args.n, args.d, args.n_test)
 
-    prob = KRRProblem(x=x_tr, y=y_tr, kernel=args.kernel, backend="xla",
+    prob = KRRProblem(x=x_tr, y=y_tr, kernel=args.kernel,
                       precision=args.precision)
     mesh = None
     if args.mesh is not None:
@@ -188,6 +190,7 @@ def main() -> None:
         "naive_sweep_estimate": round(result.info["naive_sweep_estimate"], 2),
         "records": result.records,
         "trace": result.trace,
+        **device_fields(prob.backend),
     }
     if args.kernels is not None:
         report["weight_samples"] = result.info["weight_samples"]

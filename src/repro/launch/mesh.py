@@ -4,13 +4,13 @@
 importing this module never touches jax device state — the dry-run sets
 XLA_FLAGS before the first jax call and only then builds meshes.
 
-Mesh construction goes through ``distributed.jax_compat`` so the same code
-runs on jax 0.4.x (no axis types) and 0.6+ (typed Auto axes).
+Meshes are built by ``distributed.meshes.make_mesh`` over the first
+``prod(shape)`` devices.
 """
 
 from __future__ import annotations
 
-from repro.distributed.jax_compat import make_mesh
+from repro.distributed.meshes import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):

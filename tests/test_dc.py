@@ -188,6 +188,23 @@ def test_dc_one_device_mesh_matches_sequential():
     assert par.info["collective_dispatches"] == 0.0
 
 
+@pytest.mark.parametrize("method", ["askotch", "pcg-nystrom"])
+def test_dc_mesh_shards_run_on_their_devices(method):
+    """Shard s's local solve runs on mesh device s % D: its weights live
+    there (one device in plain runs, four in the distributed-smoke job)."""
+    import jax
+
+    from repro.distributed.meshes import make_solver_mesh
+
+    mesh = make_solver_mesh("auto")
+    devices = list(mesh.devices.flat)
+    res = solve_dc(_problem(), shards=4, method=method, max_iters=20,
+                   mesh=mesh)
+    for s, sub in enumerate(res.shard_outputs):
+        assert sub.w.devices() == {devices[s % len(devices)]}
+    assert jax.device_count() == len(devices)
+
+
 def test_dc_scattered_weights_match_shard_solves():
     p = _problem()
     out = solve(p, "dc", dc_shards=3, dc_method="direct")

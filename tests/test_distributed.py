@@ -25,22 +25,12 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 sys.path.insert(0, SRC)
 
 
-def _collective_timeout_flags() -> str:
-    """The collective stuck/terminate timeouts only exist in newer XLA CPU
-    builds — older ones treat unknown XLA_FLAGS as fatal."""
-    import jax
-
-    if tuple(int(p) for p in jax.__version__.split(".")[:2]) < (0, 5):
-        return ""
-    return (" --xla_cpu_collective_call_warn_stuck_timeout_seconds=240"
-            " --xla_cpu_collective_call_terminate_timeout_seconds=600")
-
-
 def run_py(code: str, devices: int = 8, timeout: int = 900) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={devices}"
-        + _collective_timeout_flags()
+        " --xla_cpu_collective_call_warn_stuck_timeout_seconds=240"
+        " --xla_cpu_collective_call_terminate_timeout_seconds=600"
     )
     env["PYTHONPATH"] = SRC
     out = subprocess.run(
@@ -158,7 +148,7 @@ def test_elastic_checkpoint_inprocess_single_device(tmp_path):
     from jax.sharding import PartitionSpec as P
 
     from repro.checkpoint import checkpointer
-    from repro.distributed.jax_compat import make_mesh
+    from repro.distributed.meshes import make_mesh
 
     mesh_a = make_mesh((1,), ("data",))
     arr = jnp.arange(32, dtype=jnp.float32).reshape(8, 4)
@@ -245,7 +235,7 @@ def test_elastic_checkpoint_across_meshes(tmp_path):
         import json, jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P, NamedSharding
         from repro.checkpoint import checkpointer
-        from repro.distributed.jax_compat import make_mesh
+        from repro.distributed.meshes import make_mesh
         mesh_a = make_mesh((4,), ("data",))
         arr = jnp.arange(32, dtype=jnp.float32).reshape(8, 4)
         sharded = jax.device_put(arr, NamedSharding(mesh_a, P("data", None)))

@@ -14,7 +14,7 @@ import numpy as np
 import pytest
 
 from repro.core.operator import KernelOperator
-from repro.distributed.jax_compat import make_mesh
+from repro.distributed.meshes import make_mesh
 from repro.distributed.sharded_operator import ShardedKernelOperator
 
 N, D, T, B = 64, 5, 4, 12

@@ -64,6 +64,45 @@ def test_krr_solve_launcher_cli():
     assert np.isfinite(rec["test_rmse"])
 
 
+def test_krr_solve_run_names_device_and_backend():
+    """The launcher's report says where it ran and which kernel backend the
+    platform resolved to — and the in-process ``run(argv)`` is the CLI."""
+    import jax
+
+    from repro.kernels.ops import resolve_backend
+    from repro.launch import krr_solve
+
+    run = krr_solve.run(["--n", "400", "--n-test", "50", "--d", "4",
+                         "--method", "askotch", "--iters", "30"])
+    rep = run.report
+    dev = jax.devices()[0]
+    assert rep["platform"] == dev.platform
+    assert rep["device_kind"] == dev.device_kind
+    assert rep["device_count"] == jax.device_count()
+    assert rep["backend"] == resolve_backend("auto")
+    assert rep["rel_residual"] < 1.0
+    assert run.problem.n == 400 and run.x_test.shape == (50, 4)
+
+
+def test_compile_cache_dir_from_env_else_fixed(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins; otherwise the fixed .jax_cache/ at
+    the checkout root, which stays off on the CPU backend."""
+    import pathlib
+
+    import jax
+
+    from repro.launch import runtime
+
+    root = pathlib.Path(__file__).resolve().parents[1]
+    assert runtime.REPO_CACHE_DIR == root / ".jax_cache"
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/cache/from/env")
+    assert runtime.use_compile_cache() == "/cache/from/env"
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    assert runtime.use_compile_cache() is None  # tests run on the CPU
+    assert jax.config.jax_compilation_cache_dir == before
+
+
 def test_train_launcher_loss_decreases(tmp_path):
     sys.path.insert(0, SRC)
     from repro.launch import train as train_mod
