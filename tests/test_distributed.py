@@ -25,12 +25,12 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 sys.path.insert(0, SRC)
 
 
-def run_py(code: str, devices: int = 8, timeout: int = 900) -> str:
+def run_py(code: str, devices: int = 8, timeout: int = 150) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={devices}"
-        " --xla_cpu_collective_call_warn_stuck_timeout_seconds=240"
-        " --xla_cpu_collective_call_terminate_timeout_seconds=600"
+        " --xla_cpu_collective_call_warn_stuck_timeout_seconds=60"
+        " --xla_cpu_collective_call_terminate_timeout_seconds=120"
     )
     env["PYTHONPATH"] = SRC
     out = subprocess.run(

@@ -56,7 +56,7 @@ def test_krr_solve_launcher_cli():
     out = subprocess.run(
         [sys.executable, "-m", "repro.launch.krr_solve", "--n", "2000", "--d", "6",
          "--method", "askotch", "--iters", "120", "--dataset", "regression"],
-        capture_output=True, text=True, timeout=600, env=env,
+        capture_output=True, text=True, timeout=150, env=env,
     )
     assert out.returncode == 0, out.stderr[-2000:]
     rec = json.loads(out.stdout.strip().splitlines()[-1])
@@ -103,6 +103,26 @@ def test_compile_cache_dir_from_env_else_fixed(monkeypatch):
     assert jax.config.jax_compilation_cache_dir == before
 
 
+def test_openblas_pinned_to_one_thread():
+    """The root conftest.py pins OpenBLAS, where jaxlib's CPU LAPACK runs, to
+    one thread, in this process and in the children the subprocess tests
+    start with ``dict(os.environ)``."""
+    threadpoolctl = pytest.importorskip("threadpoolctl")
+    import jax
+
+    jax.block_until_ready(jax.jit(jnp.linalg.qr)(jnp.ones((160, 80))))
+    pools = [p for p in threadpoolctl.threadpool_info()
+             if p["internal_api"] == "openblas"]
+    assert pools and all(p["num_threads"] == 1 for p in pools), pools
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import os; print(os.environ.get('OPENBLAS_NUM_THREADS'))"],
+        capture_output=True, text=True, timeout=150, env=dict(os.environ),
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.strip() == "1"
+
+
 def test_train_launcher_loss_decreases(tmp_path):
     sys.path.insert(0, SRC)
     from repro.launch import train as train_mod
@@ -122,7 +142,7 @@ def test_serve_launcher_cli():
     out = subprocess.run(
         [sys.executable, "-m", "repro.launch.serve", "--arch", "llava-next-mistral-7b",
          "--reduced", "--batch", "2", "--prompt-len", "12", "--max-new", "4"],
-        capture_output=True, text=True, timeout=600, env=env,
+        capture_output=True, text=True, timeout=150, env=env,
     )
     assert out.returncode == 0, out.stderr[-2000:]
     rec = json.loads(out.stdout.strip().splitlines()[-1])
