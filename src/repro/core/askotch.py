@@ -20,20 +20,25 @@ Defaults (paper §3.2): b = n/100, r = 100, uniform sampling,
 mu_hat = lam (clipped so mu_hat <= nu_hat and mu_hat * nu_hat <= 1),
 nu_hat = n/b, eta = 1/max(L_PB, 1).
 
-The step is a single jit-able function; ``solve`` wraps it in a Python loop
-with residual tracking and checkpoint callbacks, ``solve_scan`` in a pure
-lax.scan for benchmarking.
+``make_step`` binds a problem to one module-level jitted step: the training
+rows, targets, ARLS probabilities, lam and the acceleration weights are its
+arguments, and only the config, the operator's settings and the shapes are
+static, so equal-shaped solves (new seeds, folds, a lam grid) share one
+executable.  ``solve`` calls it from a Python loop with residual tracking and
+checkpoint callbacks, ``solve_scan`` from a pure lax.scan for benchmarking.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 import time
 from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import samplers
 from repro.core.get_l import get_l
@@ -45,7 +50,8 @@ from repro.core.nystrom import (
     stable_inv_apply_setup,
     woodbury_inv_apply,
 )
-from repro.obs.metrics import record_tile_work
+from repro.kernels import ops
+from repro.obs.metrics import counter, record_tile_work
 from repro.obs.telemetry import as_telemetry
 
 
@@ -102,108 +108,152 @@ def resolve_accel_params(cfg: ASkotchConfig, n: int, lam: float) -> tuple[float,
     return mu, nu
 
 
+class _StepScalars(NamedTuple):
+    """The step's scalars that follow the problem's values, not its shapes:
+    f32 arguments of the step's executable, computed on the host.  The mixing
+    weights of Algorithm 3 come with their complements (``1 - beta``,
+    ``1 - alpha``), so each rounds to f32 once, from a host double.  ``None``
+    for Skotch."""
+
+    lam: jax.Array
+    beta: jax.Array | None = None
+    one_minus_beta: jax.Array | None = None
+    gamma: jax.Array | None = None
+    alpha: jax.Array | None = None
+    one_minus_alpha: jax.Array | None = None
+
+
+def _step_scalars(cfg: ASkotchConfig, n: int, lam: float) -> _StepScalars:
+    """lam as f32, and for ASkotch the Algorithm 3 preamble's weights."""
+    lam = float(np.float32(lam))
+    if not cfg.accelerated:
+        return _StepScalars(jnp.float32(lam))
+    beta, gamma, alpha = _accel_params(*resolve_accel_params(cfg, n, lam))
+    return _StepScalars(*(jnp.float32(v) for v in (
+        lam, beta, 1.0 - beta, gamma, alpha, 1.0 - alpha)))
+
+
+#: the ``repro.kernels.ops`` entry points a step's trace resolves; they key
+#: the step's executable, so a swapped entry point is traced, not bypassed
+_KERNEL_ENTRIES = ("kernel_matvec", "kernel_block", "kernel_matvec_multi",
+                   "kernel_block_multi")
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "op_spec", "entries"))
+def _step(x, y, probs, scalars: _StepScalars, state: SolverState, *,
+          cfg: ASkotchConfig, op_spec, entries):
+    """One Skotch/ASkotch iteration.  Arrays and scalars are arguments; the
+    static ``cfg``, the operator ``op_spec`` (its configuration over no rows)
+    and the kernel ``entries`` decide the program, with the shapes."""
+    del entries  # part of the executable's key only
+    counter("repro_askotch_step_traces_total",
+            help="traces of the Skotch/ASkotch step body").inc()
+    op = op_spec.with_points(x)
+    n = x.shape[0]
+    b = cfg.resolve_block(n)
+    r = min(cfg.rank, b - 1)
+    lam = scalars.lam
+    if cfg.sampling == "arls":
+        sampler = samplers.arls_sampler(probs, b)
+    else:
+        sampler = samplers.uniform_sampler(n, b)
+    head_axes = None if y.ndim == 1 else (0,)
+
+    key, kb, knys, kl = jax.random.split(state.key, 4)
+    idx = sampler(kb)
+    xb = jnp.take(x, idx, axis=0)
+    yb = jnp.take(y, idx, axis=0)
+    zref = state.z if cfg.accelerated else state.w
+    zb = jnp.take(zref, idx, axis=0)
+
+    # -- block build + Nystrom preconditioner (shared across heads) ---------
+    kbb = op.block(xb)
+
+    omega = jax.random.normal(knys, (b, r), dtype=kbb.dtype)
+    omega, _ = jnp.linalg.qr(omega)
+    factors = nystrom_from_sketch(kbb @ omega, omega, jnp.trace(kbb))
+
+    if cfg.rho_mode == "damped":
+        rho = lam + factors.lam[-1]
+    else:
+        rho = lam
+
+    def kbb_lam_mv(u):
+        return kbb @ u + lam * u
+
+    if cfg.precond == "identity":
+        # Ablation (paper §6.4 / Lin et al. 2024): K_hat = 0, rho = 1 =>
+        # plain sketched-gradient step with powering-estimated stepsize.
+        factors_id = NystromFactors(
+            u=jnp.zeros((b, 1), kbb.dtype), lam=jnp.zeros((1,), kbb.dtype)
+        )
+        step_l = get_l(
+            kl, kbb_lam_mv, factors_id, jnp.float32(1.0), num_iters=cfg.powering_iters
+        )
+        solve_g = lambda g: g  # noqa: E731
+    else:
+        step_l = get_l(kl, kbb_lam_mv, factors, rho, num_iters=cfg.powering_iters)
+        if cfg.stable_inv:
+            chol_l = stable_inv_apply_setup(factors, rho)
+            solve_g = lambda g: stable_inv_apply(factors, rho, chol_l, g)  # noqa: E731
+        else:
+            solve_g = lambda g: woodbury_inv_apply(factors, rho, g)  # noqa: E731
+
+    eta = 1.0 / jnp.maximum(step_l, 1.0)  # eta = 1 / hat-L_PB (Lemma 8)
+
+    # -- fused O(nbt) kernel matvec: G_B = (K_lam)_{B,:} Z - Y_B ------------
+    # one kernel-tile pass serves all t heads
+    gb = op.row_block_matvec(xb, zref) + lam * zb - yb
+    db = solve_g(gb)
+
+    # -- iterate updates (batched over the head axis) ------------------------
+    if cfg.accelerated:
+        w_new = state.z.at[idx].add(-eta * db)
+        v_new = (scalars.beta * state.v + scalars.one_minus_beta * state.z).at[idx].add(
+            -scalars.gamma * eta * db
+        )
+        z_new = scalars.alpha * v_new + scalars.one_minus_alpha * w_new
+    else:
+        w_new = state.w.at[idx].add(-eta * db)
+        v_new = w_new
+        z_new = w_new
+
+    new_state = SolverState(
+        w=w_new,
+        v=v_new,
+        z=z_new,
+        key=key,
+        it=state.it + 1,
+        sketch_res=jnp.linalg.norm(gb, axis=head_axes),
+    )
+    return new_state, StepAux(step_l=step_l, rho=rho)
+
+
 def make_step(
     problem: KRRProblem, cfg: ASkotchConfig, probs: jax.Array | None = None
 ) -> Callable[[SolverState], tuple[SolverState, StepAux]]:
-    """Build the jit-able Skotch/ASkotch step for a fixed problem.
+    """The Skotch/ASkotch step for ``problem``: ``step(state) -> (state, aux)``.
+
+    It calls one jitted step whose arguments are the training rows, the
+    targets, ``probs`` and :class:`_StepScalars`, and whose static part is
+    ``cfg``, the operator's configuration and the kernel entry points; so one
+    executable serves every problem of the same shapes and settings.
 
     The step is shape-polymorphic in the RHS: with y (n, t) every per-block
     quantity batches over the trailing head axis while the block sample, the
     Nystrom preconditioner, and the fused kernel tiles are computed once.
     """
-    n = problem.n
-    b = cfg.resolve_block(n)
-    r = min(cfg.rank, b - 1)
-    lam = jnp.float32(problem.lam)
-    op = dataclasses.replace(problem.op, backend=cfg.backend)
-
     if cfg.sampling == "arls":
         if probs is None:
             raise ValueError("ARLS sampling requires precomputed probs")
-        sampler = samplers.arls_sampler(probs, b)
-    elif cfg.sampling == "uniform":
-        sampler = samplers.uniform_sampler(n, b)
-    else:
+    elif cfg.sampling != "uniform":
         raise ValueError(f"unknown sampling {cfg.sampling!r}")
-
-    if cfg.accelerated:
-        mu, nu = resolve_accel_params(cfg, n, float(lam))
-        beta, gamma, alpha = _accel_params(mu, nu)
-
-    x, y = problem.x, problem.y
-    head_axes = None if y.ndim == 1 else (0,)
-
-    def step(state: SolverState) -> tuple[SolverState, StepAux]:
-        key, kb, knys, kl = jax.random.split(state.key, 4)
-        idx = sampler(kb)
-        xb = jnp.take(x, idx, axis=0)
-        yb = jnp.take(y, idx, axis=0)
-        zref = state.z if cfg.accelerated else state.w
-        zb = jnp.take(zref, idx, axis=0)
-
-        # -- block build + Nystrom preconditioner (shared across heads) -----
-        kbb = op.block(xb)
-
-        omega = jax.random.normal(knys, (b, r), dtype=kbb.dtype)
-        omega, _ = jnp.linalg.qr(omega)
-        factors = nystrom_from_sketch(kbb @ omega, omega, jnp.trace(kbb))
-
-        if cfg.rho_mode == "damped":
-            rho = lam + factors.lam[-1]
-        else:
-            rho = lam
-
-        def kbb_lam_mv(u):
-            return kbb @ u + lam * u
-
-        if cfg.precond == "identity":
-            # Ablation (paper §6.4 / Lin et al. 2024): K_hat = 0, rho = 1 =>
-            # plain sketched-gradient step with powering-estimated stepsize.
-            factors_id = NystromFactors(
-                u=jnp.zeros((b, 1), kbb.dtype), lam=jnp.zeros((1,), kbb.dtype)
-            )
-            step_l = get_l(
-                kl, kbb_lam_mv, factors_id, jnp.float32(1.0), num_iters=cfg.powering_iters
-            )
-            solve_g = lambda g: g  # noqa: E731
-        else:
-            step_l = get_l(kl, kbb_lam_mv, factors, rho, num_iters=cfg.powering_iters)
-            if cfg.stable_inv:
-                chol_l = stable_inv_apply_setup(factors, rho)
-                solve_g = lambda g: stable_inv_apply(factors, rho, chol_l, g)  # noqa: E731
-            else:
-                solve_g = lambda g: woodbury_inv_apply(factors, rho, g)  # noqa: E731
-
-        eta = 1.0 / jnp.maximum(step_l, 1.0)  # eta = 1 / hat-L_PB (Lemma 8)
-
-        # -- fused O(nbt) kernel matvec: G_B = (K_lam)_{B,:} Z - Y_B --------
-        # one kernel-tile pass serves all t heads
-        gb = op.row_block_matvec(xb, zref) + lam * zb - yb
-        db = solve_g(gb)
-
-        # -- iterate updates (batched over the head axis) --------------------
-        if cfg.accelerated:
-            w_new = state.z.at[idx].add(-eta * db)
-            v_new = (beta * state.v + (1.0 - beta) * state.z).at[idx].add(
-                -gamma * eta * db
-            )
-            z_new = alpha * v_new + (1.0 - alpha) * w_new
-        else:
-            w_new = state.w.at[idx].add(-eta * db)
-            v_new = w_new
-            z_new = w_new
-
-        new_state = SolverState(
-            w=w_new,
-            v=v_new,
-            z=z_new,
-            key=key,
-            it=state.it + 1,
-            sketch_res=jnp.linalg.norm(gb, axis=head_axes),
-        )
-        return new_state, StepAux(step_l=step_l, rho=rho)
-
-    return step
+    op = dataclasses.replace(problem.op, backend=cfg.backend)
+    return functools.partial(
+        _step, op.x, problem.y, probs, _step_scalars(cfg, problem.n, problem.lam),
+        cfg=cfg, op_spec=op.with_points(None),
+        entries=tuple(getattr(ops, name) for name in _KERNEL_ENTRIES),
+    )
 
 
 def init_state(problem: KRRProblem, seed: int = 0, w0: jax.Array | None = None) -> SolverState:
@@ -274,7 +324,7 @@ def solve(
     precision = getattr(problem.op, "precision", "f32")
     recorder = tel.recorder(solver_name, precision=precision, n=n)
     probs = _maybe_arls_probs(problem, cfg, seed)
-    step = jax.jit(make_step(problem, cfg, probs))
+    step = make_step(problem, cfg, probs)  # already jitted: called as it is
     state = init_state(problem, seed, w0)
     history = recorder.history
     tel_enabled = tel.enabled  # hoisted: the loop pays one bool test
