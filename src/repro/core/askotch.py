@@ -51,6 +51,7 @@ from repro.core.nystrom import (
     woodbury_inv_apply,
 )
 from repro.kernels import ops
+from repro.kernels.precision import MATMUL_PRECISION, solver_matmuls
 from repro.obs.metrics import counter, record_tile_work
 from repro.obs.telemetry import as_telemetry
 
@@ -144,88 +145,106 @@ def _step(x, y, probs, scalars: _StepScalars, state: SolverState, *,
           cfg: ASkotchConfig, op_spec, entries):
     """One Skotch/ASkotch iteration.  Arrays and scalars are arguments; the
     static ``cfg``, the operator ``op_spec`` (its configuration over no rows)
-    and the kernel ``entries`` decide the program, with the shapes."""
+    and the kernel ``entries`` decide the program, with the shapes.  The
+    operator's precision policy sets the precision of the step's dense
+    algebra (``MATMUL_PRECISION``: full f32 under "f32")."""
     del entries  # part of the executable's key only
     counter("repro_askotch_step_traces_total",
+            labels={"precision": MATMUL_PRECISION[op_spec.precision]},
             help="traces of the Skotch/ASkotch step body").inc()
-    op = op_spec.with_points(x)
+    with solver_matmuls(op_spec.precision):
+        return _step_body(x, y, probs, scalars, state, cfg, op_spec.with_points(x))
+
+
+def _step_body(x, y, probs, scalars: _StepScalars, state: SolverState,
+               cfg: ASkotchConfig, op) -> tuple[SolverState, StepAux]:
+    """The iteration itself, its phases under stable ``askotch/...`` scopes
+    (``sample``, ``precond``, ``row_block``, ``update``), which name its
+    operations in a profile."""
     n = x.shape[0]
     b = cfg.resolve_block(n)
     r = min(cfg.rank, b - 1)
     lam = scalars.lam
-    if cfg.sampling == "arls":
-        sampler = samplers.arls_sampler(probs, b)
-    else:
-        sampler = samplers.uniform_sampler(n, b)
     head_axes = None if y.ndim == 1 else (0,)
 
-    key, kb, knys, kl = jax.random.split(state.key, 4)
-    idx = sampler(kb)
-    xb = jnp.take(x, idx, axis=0)
-    yb = jnp.take(y, idx, axis=0)
-    zref = state.z if cfg.accelerated else state.w
-    zb = jnp.take(zref, idx, axis=0)
+    with jax.named_scope("askotch/sample"):
+        if cfg.sampling == "arls":
+            sampler = samplers.arls_sampler(probs, b)
+        else:
+            sampler = samplers.uniform_sampler(n, b)
+        key, kb, knys, kl = jax.random.split(state.key, 4)
+        idx = sampler(kb)
+        xb = jnp.take(x, idx, axis=0)
+        yb = jnp.take(y, idx, axis=0)
+        zref = state.z if cfg.accelerated else state.w
+        zb = jnp.take(zref, idx, axis=0)
 
     # -- block build + Nystrom preconditioner (shared across heads) ---------
-    kbb = op.block(xb)
+    with jax.named_scope("askotch/precond"):
+        kbb = op.block(xb)
 
-    omega = jax.random.normal(knys, (b, r), dtype=kbb.dtype)
-    omega, _ = jnp.linalg.qr(omega)
-    factors = nystrom_from_sketch(kbb @ omega, omega, jnp.trace(kbb))
+        omega = jax.random.normal(knys, (b, r), dtype=kbb.dtype)
+        omega, _ = jnp.linalg.qr(omega)
+        factors = nystrom_from_sketch(kbb @ omega, omega, jnp.trace(kbb))
 
-    if cfg.rho_mode == "damped":
-        rho = lam + factors.lam[-1]
-    else:
-        rho = lam
-
-    def kbb_lam_mv(u):
-        return kbb @ u + lam * u
-
-    if cfg.precond == "identity":
-        # Ablation (paper §6.4 / Lin et al. 2024): K_hat = 0, rho = 1 =>
-        # plain sketched-gradient step with powering-estimated stepsize.
-        factors_id = NystromFactors(
-            u=jnp.zeros((b, 1), kbb.dtype), lam=jnp.zeros((1,), kbb.dtype)
-        )
-        step_l = get_l(
-            kl, kbb_lam_mv, factors_id, jnp.float32(1.0), num_iters=cfg.powering_iters
-        )
-        solve_g = lambda g: g  # noqa: E731
-    else:
-        step_l = get_l(kl, kbb_lam_mv, factors, rho, num_iters=cfg.powering_iters)
-        if cfg.stable_inv:
-            chol_l = stable_inv_apply_setup(factors, rho)
-            solve_g = lambda g: stable_inv_apply(factors, rho, chol_l, g)  # noqa: E731
+        if cfg.rho_mode == "damped":
+            rho = lam + factors.lam[-1]
         else:
-            solve_g = lambda g: woodbury_inv_apply(factors, rho, g)  # noqa: E731
+            rho = lam
 
-    eta = 1.0 / jnp.maximum(step_l, 1.0)  # eta = 1 / hat-L_PB (Lemma 8)
+        def kbb_lam_mv(u):
+            return kbb @ u + lam * u
+
+        if cfg.precond == "identity":
+            # Ablation (paper §6.4 / Lin et al. 2024): K_hat = 0, rho = 1 =>
+            # plain sketched-gradient step with powering-estimated stepsize.
+            factors_id = NystromFactors(
+                u=jnp.zeros((b, 1), kbb.dtype), lam=jnp.zeros((1,), kbb.dtype)
+            )
+            step_l = get_l(
+                kl, kbb_lam_mv, factors_id, jnp.float32(1.0), num_iters=cfg.powering_iters
+            )
+            solve_g = lambda g: g  # noqa: E731
+        else:
+            step_l = get_l(kl, kbb_lam_mv, factors, rho, num_iters=cfg.powering_iters)
+            if cfg.stable_inv:
+                chol_l = stable_inv_apply_setup(factors, rho)
+                solve_g = lambda g: stable_inv_apply(factors, rho, chol_l, g)  # noqa: E731
+            else:
+                solve_g = lambda g: woodbury_inv_apply(factors, rho, g)  # noqa: E731
+
+        eta = 1.0 / jnp.maximum(step_l, 1.0)  # eta = 1 / hat-L_PB (Lemma 8)
 
     # -- fused O(nbt) kernel matvec: G_B = (K_lam)_{B,:} Z - Y_B ------------
     # one kernel-tile pass serves all t heads
-    gb = op.row_block_matvec(xb, zref) + lam * zb - yb
-    db = solve_g(gb)
+    with jax.named_scope("askotch/row_block"):
+        gb = op.row_block_matvec(xb, zref) + lam * zb - yb
+
+    # the preconditioner's apply (Woodbury or stable Cholesky) is its cost
+    with jax.named_scope("askotch/precond"):
+        db = solve_g(gb)
 
     # -- iterate updates (batched over the head axis) ------------------------
-    if cfg.accelerated:
-        w_new = state.z.at[idx].add(-eta * db)
-        v_new = (scalars.beta * state.v + scalars.one_minus_beta * state.z).at[idx].add(
-            -scalars.gamma * eta * db
-        )
-        z_new = scalars.alpha * v_new + scalars.one_minus_alpha * w_new
-    else:
-        w_new = state.w.at[idx].add(-eta * db)
-        v_new = w_new
-        z_new = w_new
+    with jax.named_scope("askotch/update"):
+        if cfg.accelerated:
+            w_new = state.z.at[idx].add(-eta * db)
+            v_new = (scalars.beta * state.v + scalars.one_minus_beta * state.z).at[idx].add(
+                -scalars.gamma * eta * db
+            )
+            z_new = scalars.alpha * v_new + scalars.one_minus_alpha * w_new
+        else:
+            w_new = state.w.at[idx].add(-eta * db)
+            v_new = w_new
+            z_new = w_new
 
-    new_state = SolverState(
-        w=w_new,
-        v=v_new,
-        z=z_new,
-        key=key,
-        it=state.it + 1,
-        sketch_res=jnp.linalg.norm(gb, axis=head_axes),
-    )
+        new_state = SolverState(
+            w=w_new,
+            v=v_new,
+            z=z_new,
+            key=key,
+            it=state.it + 1,
+            sketch_res=jnp.linalg.norm(gb, axis=head_axes),
+        )
     return new_state, StepAux(step_l=step_l, rho=rho)
 
 
@@ -311,6 +330,8 @@ def solve(
     the per-head and aggregate reports), so it is only computed every
     ``eval_every`` iterations (and at the end).  History records carry
     ``rel_residual`` (aggregate over heads) and ``rel_residual_per_head``.
+    A check with a non-finite residual on any head ends the solve, not
+    converged (``repro_solve_nonfinite_stops_total``).
 
     ``telemetry`` (a ``repro.obs.Telemetry``) adds a solve span, canonical
     trace events mirroring the history records, and per-iteration tile-work
@@ -343,9 +364,10 @@ def solve(
             if it % eval_every == 0 or it == max_iters:
                 rel_agg, rel_heads = problem.residual_report(state.w)
                 rel = float(rel_agg)
+                heads = [float(v) for v in rel_heads]
                 rec = recorder.add(
                     it, rel,
-                    rel_residual_per_head=[float(v) for v in rel_heads],
+                    rel_residual_per_head=heads,
                     sketch_res=float(jnp.linalg.norm(state.sketch_res)),
                     step_L=float(aux.step_l),
                     time_s=time.perf_counter() - t0,
@@ -357,6 +379,11 @@ def solve(
                 # the same convergence meaning as blocked_cg
                 if bool(jnp.all(rel_heads < tol)):
                     converged = True
+                    break
+                # a diverged iterate never comes back: stop at once
+                if not all(math.isfinite(v) for v in heads):
+                    counter("repro_solve_nonfinite_stops_total",
+                            help="solves stopped on a non-finite residual").inc()
                     break
             if time_budget_s is not None and time.perf_counter() - t0 > time_budget_s:
                 break

@@ -34,6 +34,7 @@ from repro.core.krr import KRRProblem
 from repro.core.nystrom import NystromFactors, nystrom_from_sketch
 from repro.core.operator import as_multirhs, maybe_squeeze
 from repro.core.rpcholesky import rp_cholesky
+from repro.kernels.precision import solver_matmuls
 from repro.obs.metrics import record_tile_work
 from repro.obs.telemetry import as_telemetry
 
@@ -82,24 +83,28 @@ def make_preconditioner(
     lam = jnp.float32(problem.lam)
     if kind == "identity":
         return lambda v: v
-    if kind == "nystrom":
-        f = _nystrom_full(problem, rank, jax.random.PRNGKey(seed))
-    elif kind == "rff":
-        f = _rff_full(problem, rank, jax.random.PRNGKey(seed))
-    elif kind == "rpcholesky":
-        fmat, _ = rp_cholesky(jax.random.PRNGKey(seed), problem.op, rank)
-        u, s, _ = jnp.linalg.svd(fmat, full_matrices=False)
-        f = NystromFactors(u=u, lam=s * s)
-    else:
-        raise ValueError(f"unknown preconditioner {kind!r}")
+    # the factors and their apply at the problem's precision (full f32
+    # under "f32"; a TPU's default is one bf16 pass)
+    with solver_matmuls(problem.precision):
+        if kind == "nystrom":
+            f = _nystrom_full(problem, rank, jax.random.PRNGKey(seed))
+        elif kind == "rff":
+            f = _rff_full(problem, rank, jax.random.PRNGKey(seed))
+        elif kind == "rpcholesky":
+            fmat, _ = rp_cholesky(jax.random.PRNGKey(seed), problem.op, rank)
+            u, s, _ = jnp.linalg.svd(fmat, full_matrices=False)
+            f = NystromFactors(u=u, lam=s * s)
+        else:
+            raise ValueError(f"unknown preconditioner {kind!r}")
 
     rho = lam + f.lam[-1] if rho_mode == "damped" else lam
     coeff = (f.lam[-1] + rho) / (f.lam + rho)
 
     def apply(v: jax.Array) -> jax.Array:
-        utv = f.u.T @ v
-        scaled = utv * (coeff[:, None] if v.ndim == 2 else coeff)
-        return f.u @ scaled + (v - f.u @ utv)
+        with solver_matmuls(problem.precision):
+            utv = f.u.T @ v
+            scaled = utv * (coeff[:, None] if v.ndim == 2 else coeff)
+            return f.u @ scaled + (v - f.u @ utv)
 
     return apply
 

@@ -55,7 +55,7 @@ from repro.core.nystrom import nystrom_from_sketch
 from repro.core.operator import as_multirhs, maybe_squeeze
 from repro.distributed.meshes import shard_map
 from repro.distributed.sharded_operator import ShardedKernelOperator
-from repro.kernels.precision import PRECISIONS
+from repro.kernels.precision import PRECISIONS, solver_matmuls
 
 BACKENDS = ("auto", "xla", "pallas", "interpret")
 
@@ -250,10 +250,16 @@ def make_dist_askotch_step(mesh: Mesh, cfg: DistKRRConfig):
         return DistState(w=w_new, v=v_new, z=z_new, key=key, sketch_res=sk_res,
                          pv=pv)
 
+    def local_at_precision(state: DistState, x_l, y_l):
+        # the step's dense algebra at the problem's precision (full f32 under
+        # "f32"), as in the single-device step
+        with solver_matmuls(cfg.precision):
+            return local(state, x_l, y_l)
+
     vec = op.vec_spec(1 if t == 1 else 2)
     state_specs = DistState(w=vec, v=vec, z=vec, key=P(), sketch_res=P(), pv=P())
     step = shard_map(
-        local,
+        local_at_precision,
         mesh=mesh,
         in_specs=(state_specs, P(rows, None), vec),
         out_specs=state_specs,
@@ -419,17 +425,19 @@ def solve_pcg_dist(
     pinv = None
     if precond == "nystrom":
         r = min(rank, problem.n)
-        omega = jax.random.normal(jax.random.PRNGKey(seed), (problem.n, r),
-                                  jnp.float32)
-        omega, _ = jnp.linalg.qr(omega)
-        omega = jax.device_put(omega, bound.sharding(2))
-        f = nystrom_from_sketch(bound.sketch(omega), omega, bound.trace_est())
+        with solver_matmuls(problem.precision):
+            omega = jax.random.normal(jax.random.PRNGKey(seed), (problem.n, r),
+                                      jnp.float32)
+            omega, _ = jnp.linalg.qr(omega)
+            omega = jax.device_put(omega, bound.sharding(2))
+            f = nystrom_from_sketch(bound.sketch(omega), omega, bound.trace_est())
         rho = lam + f.lam[-1] if rho_mode == "damped" else lam
         coeff = (f.lam[-1] + rho) / (f.lam + rho)
 
         def apply(v: jax.Array) -> jax.Array:
-            utv = f.u.T @ v
-            return f.u @ (utv * coeff[:, None]) + (v - f.u @ utv)
+            with solver_matmuls(problem.precision):
+                utv = f.u.T @ v
+                return f.u @ (utv * coeff[:, None]) + (v - f.u @ utv)
 
         pinv = jax.jit(apply)
 

@@ -25,7 +25,8 @@ from repro.core.krr import KRRProblem
 from repro.kernels import ops
 from repro.obs import metrics
 
-TRACES = "repro_askotch_step_traces_total"
+#: traces of the f32 step, whose dense algebra runs at full f32
+TRACES = 'repro_askotch_step_traces_total{precision="highest"}'
 SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")
 OPTIONS = dict(max_iters=40, eval_every=20, tol=1e-12, block_size=48, rank=12)
 

@@ -1,6 +1,7 @@
 """Ahead-of-time compiles for a described TPU v5e: the Pallas tiles of the
-main path, every kernel family in f32 and bf16, and the sharded ASkotch
-step on a 4-chip mesh.
+main path, every kernel family in f32 and bf16, the sharded ASkotch step on
+a 4-chip mesh, and the tiles and the ASkotch step of the 10-class, d = 784
+one-vs-all deployment on one chip.
 
 Nothing runs: the TPU compiler, which is installed with jax, compiles for a
 topology that is described and not attached.  That catches what the
@@ -19,6 +20,8 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, SingleDeviceSharding
 
+from repro.core import askotch
+from repro.core.krr import KRRProblem
 from repro.distributed.krr_dist import (
     DistKRRConfig,
     abstract_dist_inputs,
@@ -40,6 +43,12 @@ MATVEC_SHAPES = {
     "row_block_t16": (B, 16),
     "serving_bucket": (8, 1),
 }
+
+#: the one-vs-all deployment (bench/configs/ova10-d784.json): MNIST's n and
+#: d, ten heads, askotch's default block n/100
+OVA_N, OVA_D, OVA_T, OVA_B = 60_000, 784, 10, 600
+#: (rows m, heads t) of its matvecs: the full-residual check, the row block
+OVA_MATVEC_SHAPES = {"residual": (OVA_N, OVA_T), "row_block": (OVA_B, OVA_T)}
 
 
 @pytest.fixture(scope="module")
@@ -129,3 +138,45 @@ def test_dist_askotch_step_pallas_compiles_for_v5e_4x1(topo):
     state = jax.tree.map(place, state, sh["state"])
     text = _compiled_text(step, state, place(x, sh["x"]), place(y, sh["y"]))
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("shape", list(OVA_MATVEC_SHAPES))
+def test_ova10_kernel_matvec_compiles_for_v5e(one_chip, shape):
+    m, t = OVA_MATVEC_SHAPES[shape]
+    text = _compiled_text(
+        lambda a, b, v: kernel_matvec_pallas(a, b, v, kernel="rbf", sigma=63.0),
+        _f32((m, OVA_D), one_chip), _f32((OVA_N, OVA_D), one_chip),
+        _f32((OVA_N, t), one_chip),
+    )
+    assert "tpu_custom_call" in text
+
+
+def test_ova10_kernel_block_compiles_for_v5e(one_chip):
+    text = _compiled_text(
+        lambda a: kernel_block_pallas(a, a, kernel="rbf", sigma=63.0),
+        _f32((OVA_B, OVA_D), one_chip),
+    )
+    assert "tpu_custom_call" in text
+
+
+def test_ova10_askotch_step_compiles_for_v5e(one_chip):
+    """The single-device step at the deployment's shapes, its dense algebra
+    at full f32; the training set is an argument, so a small stand-in builds
+    the step and the described shapes replace it."""
+    problem = KRRProblem(x=jnp.zeros((8, OVA_D)), y=jnp.zeros((8, OVA_T)),
+                         sigma=63.0, lam_unscaled=1e-6, backend="pallas")
+    cfg = askotch.ASkotchConfig(block_size=OVA_B, rank=100, backend="pallas")
+    step = askotch.make_step(problem, cfg)
+
+    def described(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    state = askotch.init_state(problem)._replace(
+        w=jnp.zeros((OVA_N, OVA_T)), v=jnp.zeros((OVA_N, OVA_T)),
+        z=jnp.zeros((OVA_N, OVA_T)))
+    compiled = step.func.lower(
+        _f32((OVA_N, OVA_D), one_chip), _f32((OVA_N, OVA_T), one_chip), None,
+        jax.tree.map(described, step.args[3]), jax.tree.map(described, state),
+        **step.keywords,
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
